@@ -13,6 +13,8 @@ import json
 import sys
 import traceback
 
+from repro.core.search.backend import enable_compile_cache
+
 from . import common
 
 BENCHES = [
@@ -54,6 +56,7 @@ def main() -> None:
     if unknown:
         print(f"usage: python -m benchmarks.run [--smoke] (unknown: {unknown})", file=sys.stderr)
         sys.exit(2)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     common.ROWS.clear()
     failed = []

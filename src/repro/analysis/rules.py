@@ -49,7 +49,8 @@ Rule inventory
     wrong-results territory.
 
 ``x64-scope``
-    ``jax.config.update`` / ``enable_x64`` outside the one scoped helper
+    ``jax.config.update``, ``jax.enable_x64(...)`` calls, or an
+    ``enable_x64`` import outside the one scoped helper
     (``search/backend.py``).  A process-wide x64 flip would poison the
     float32 Pallas kernels; the scoped context is the only sanctioned way.
 
@@ -685,6 +686,14 @@ def _check_x64_scope(tree: ast.AST, ctx: RuleContext) -> List[Violation]:
                         "`jax.config.update` outside search/backend.py — "
                         "process-wide config flips poison the float32 "
                         "kernels; use backend.x64()",
+                    )
+                )
+            elif dotted is not None and dotted.split(".")[-1] == "enable_x64":
+                out.append(
+                    _v(
+                        ctx, node, "x64-scope",
+                        f"`{dotted}(...)` outside search/backend.py; "
+                        "use the scoped backend.x64() helper",
                     )
                 )
         elif isinstance(node, ast.ImportFrom):

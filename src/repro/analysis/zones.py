@@ -26,8 +26,9 @@ from typing import Tuple
 #: unsorted is exactly the hazard the iter-order rule exists to catch.
 SET_ATTRS: Tuple[str, ...] = ("dims", "soft_dims", "hard")
 
-#: The one module allowed to touch jax float64 config: the scoped
-#: ``enable_x64`` helper.  Everything else must use ``backend.x64()``.
+#: The one module allowed to touch jax config: the scoped
+#: ``jax.enable_x64(True)`` helper and the compile-cache setup.  Everything
+#: else must use ``backend.x64()`` / ``backend.enable_compile_cache()``.
 X64_ALLOWED: Tuple[str, ...] = ("repro/core/search/backend.py",)
 
 

@@ -25,10 +25,13 @@ correctly-rounded IEEE arithmetic on identical bits.  The kernel is
 therefore bit-identical to the numpy and jax-vmap oracles — pinned by
 ``tests/test_search_kernels.py`` over the §6 topology suite.
 
-Deployment note: the kernel body uses jnp gather/scatter (``x.at[].add``,
-advanced-index gathers), which interpret mode (and any XLA backend)
-executes exactly; a Mosaic-TPU lowering would replace them with the
-one-hot/matmul formulation — a recorded ROADMAP follow-up.  Committed
+Deployment note: the kernel does not compile for a TPU.  Its accumulators
+and outputs are float64, and its body uses jnp gather/scatter
+(``x.at[].add``, advanced-index gathers); the Mosaic lowering refuses
+both (``tests/test_chip_compile.py`` pins the refusal).  Interpret mode
+executes it exactly off-TPU; on a TPU the compiled path raises
+:data:`~repro.core.search.backend.PALLAS_ON_TPU_ERROR` before lowering.
+The 32-bit one-hot/matmul rewrite is ROADMAP Speed item 3.  Committed
 call sites must not hard-code ``interpret=True`` (the ``pallas-interpret``
 lint rule): the default is computed from the runtime platform by
 :func:`default_interpret`.
@@ -41,7 +44,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from ..backend import jax_modules, x64
+from ..backend import PALLAS_ON_TPU_ERROR, jax_modules, on_tpu, x64
 from ..batch import BatchArena
 from ..throughput import ThroughputModel, ack_lambda, edge_lat_class, hard_lambda
 
@@ -56,8 +59,7 @@ def default_interpret() -> bool:
     this instead of hard-coding ``interpret=True`` (lint: pallas-interpret).
     Interpret mode executes the kernel through XLA with float64 intact,
     which is exactly what the golden-equality contract needs on CPU."""
-    jax, _ = jax_modules()
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _fused_kernel(
@@ -288,6 +290,8 @@ def fused_score(
     if block_b < 1:
         raise ValueError(f"block_b must be >= 1, got {block_b}")
     interp = default_interpret() if interpret is None else bool(interpret)
+    if not interp and on_tpu():
+        raise RuntimeError(PALLAS_ON_TPU_ERROR)
     # Pad the batch to a block multiple with node-0 rows; the padded rows
     # score garbage that never leaves this function.
     n_blocks = -(-B // block_b)

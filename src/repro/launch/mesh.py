@@ -31,4 +31,7 @@ def make_smoke_mesh(devices=None):
     n = len(devices)
     model = 2 if n % 2 == 0 and n > 1 else 1
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    # Auto axes: the planner's rules are constraints the compiler propagates
+    # (jax.make_mesh defaults to Explicit axes, which type every op's sharding).
+    auto = (jax.sharding.AxisType.Auto,) * 2
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=auto)

@@ -433,6 +433,19 @@ def test_x64_scope_import_form():
     assert rules_hit(src, CORE) == {"x64-scope"}
 
 
+def test_x64_scope_context_manager_call():
+    src = """
+    import jax
+
+    def scoped(x):
+        with jax.enable_x64(True):
+            return x
+    """
+    assert rules_hit(src, CORE) == {"x64-scope"}
+    assert rules_hit(src, HARNESS) == {"x64-scope"}
+    assert rules_hit(src, "src/repro/core/search/backend.py") == set()
+
+
 # --------------------------------------------------------------------------
 # hot-loop
 # --------------------------------------------------------------------------

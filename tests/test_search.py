@@ -8,6 +8,8 @@ jax/numpy golden equality, and the control-plane integration
 (registry kwargs, Nimbus plan/submit/rebalance, ScenarioRunner replay).
 """
 
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -260,6 +262,38 @@ def test_search_backends_agree_end_to_end():
         topology, cluster, commit=False
     )
     assert a.placements == b.placements
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax not installed")
+def test_x64_is_scoped():
+    import jax.numpy as jnp
+
+    from repro.core.search.backend import x64
+
+    with x64():
+        assert jnp.zeros(1).dtype == jnp.float64
+    assert jnp.zeros(1).dtype == jnp.float32
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax not installed")
+def test_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch):
+    import jax
+
+    from repro.core.search import backend
+
+    prev = jax.config.jax_compilation_cache_dir
+    root = Path(backend.__file__).resolve().parents[4]
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert backend.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev  # nothing set
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = backend.enable_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (root / ".gitignore").read_text().splitlines()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_search_degrades_to_greedy_on_trivial_topology():

@@ -223,6 +223,38 @@ def test_multi_swap_pallas_backend_and_validation():
         BatchAnnealer(ba, backend="numpy").run(P0, 20, seed=1, multi_swap=0)
 
 
+def test_pallas_backend_refused_on_tpu_before_lowering(monkeypatch):
+    """On a TPU the float64 kernel cannot lower: every pallas entry point
+    raises the named error up front (never a Mosaic exception mid-search),
+    while backend='jax' stays available."""
+    from repro.api import Nimbus, SchedulingPayload
+    from repro.core.search.backend import PALLAS_ON_TPU_ERROR, resolve_backend
+
+    ba, tm = kernel_case(lambda: T.linear(True))
+    P = random_batch(ba, 4, seed=2)
+    payload = SchedulingPayload.from_dict({
+        "topology": {"id": "t", "components": [
+            {"id": "s", "is_spout": True, "parallelism": 2},
+            {"id": "b", "parallelism": 2}], "edges": [{"src": "s", "dst": "b"}]},
+        "cluster": {"preset": "emulab_12"},
+        "scheduler": {"name": "rstorm-search", "kwargs": {"backend": "pallas"}},
+    })
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend("jax") == "jax"
+    calls = (
+        lambda: resolve_backend("pallas"),
+        lambda: evaluate_batch(ba, P, backend="pallas", throughput_model=tm),
+        lambda: throughput_batch(ba, tm, P, backend="pallas"),
+        lambda: BatchAnnealer(ba, backend="pallas"),
+        lambda: fused_score(ba, P, tm=tm),
+        lambda: Nimbus().plan(payload),
+    )
+    for call in calls:
+        with pytest.raises(RuntimeError) as err:
+            call()
+        assert str(err.value) == PALLAS_ON_TPU_ERROR
+
+
 # --------------------------------------------------------------------------
 # property-style shape fuzzing (runs only where hypothesis is installed)
 # --------------------------------------------------------------------------
