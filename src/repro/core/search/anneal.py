@@ -33,9 +33,9 @@ import numpy as np
 
 from ...obs import get_hub
 from ..engine.arena import swap_network_delta, swap_overload_delta
-from .backend import jax_modules, resolve_backend, x64
+from .backend import fetch, jax_modules, resolve_backend, x64
 from .batch import BatchArena
-from .objective import OVERLOAD_PENALTY, evaluate_batch
+from .objective import OVERLOAD_PENALTY
 from .throughput import (
     ThroughputModel,
     ack_lambda,
@@ -52,85 +52,6 @@ OBJECTIVES = ("netcost", "throughput")
 #: swaps that worsen the placement by up to this much, escaping the greedy
 #: seed's local minimum; anneals linearly to 0.
 DEFAULT_T0 = 2.0
-
-#: Max best-so-far curve points per annealer run when a MetricsHub is
-#: active.  Curve marks land on multiples of the jax path's fused block
-#: size, so an instrumented run replays the *exact* uninstrumented chain:
-#: the jitted steps return their full carry, and chains split across call
-#: boundaries never diverge (see ``_jax_anneal_tp_fn``).
-CURVE_POINTS = 8
-
-
-def _curve_marks(steps: int, k: int, n_points: int = CURVE_POINTS) -> list:
-    """Ascending proposal counts (multiples of ``k``; the final step always
-    included) at which the best-so-far objective curve is sampled."""
-    k = max(1, min(k, steps))
-    blocks = steps // k
-    marks = sorted({(blocks * p // n_points) * k for p in range(1, n_points + 1)} - {0})
-    if steps not in marks:
-        marks.append(steps)
-    return marks
-
-
-def _mark_segments(lo: int, hi: int, marks):
-    """Split the proposal range [lo, hi) at any interior curve marks."""
-    if not marks:
-        yield lo, hi
-        return
-    prev = lo
-    for m in marks:
-        if lo < m < hi:
-            yield prev, m
-            prev = m
-    yield prev, hi
-
-
-class _AnnealObs:
-    """Hub-enabled annealer instrumentation (``repro.obs``): a best-so-far
-    objective curve on the proposal-count axis plus per-chain acceptance.
-    Pure read-side — it evaluates placements the chains already produced,
-    so recording never perturbs a chain."""
-
-    def __init__(self, hub, ba: BatchArena, objective: str, tm) -> None:
-        self.hub = hub
-        self.ba = ba
-        self.objective = objective
-        self.tm = tm
-        self.best: Optional[float] = None
-        self.series = hub.series("search.best_objective", objective=objective)
-
-    def point(self, n_swaps: int, P, state=None, tp=None) -> None:
-        if self.objective == "throughput":
-            if tp is None:
-                # Carried jax aggregates are exact (grid-quantized), so the
-                # host-side proxy equals the in-scan carried value.
-                vals = [np.asarray(s) for s in state]
-                tp = proxy_from_state(*vals, self.tm)
-            cur = float(np.max(tp))
-            self.best = cur if self.best is None else max(self.best, cur)
-        else:
-            ev = evaluate_batch(
-                self.ba, np.asarray(P).astype(np.intp), backend="numpy"
-            )
-            cur = float(np.min(ev.penalized()))
-            self.best = cur if self.best is None else min(self.best, cur)
-        self.series.append(n_swaps, self.best)
-
-    def finish(self, acc: np.ndarray, steps: int) -> None:
-        acc = np.asarray(acc, dtype=np.int64)
-        n_chains = acc.shape[0]
-        total = int(acc.sum(dtype=np.int64))
-        self.hub.counter("search.proposals").inc(steps * n_chains)
-        self.hub.counter("search.accepted").inc(total)
-        self.hub.gauge("search.accept_rate", objective=self.objective).set(
-            total / max(steps * n_chains, 1)
-        )
-        rates = self.hub.series(
-            "search.chain_accept_rate", objective=self.objective
-        )
-        for b in range(n_chains):
-            rates.append(b, int(acc[b]) / max(steps, 1))
-
 
 def move_delta(move_cost, move_base, i, j, na, nb, xp=np):
     """Δ(migration term) for swapping tasks ``i``/``j`` between nodes
@@ -212,38 +133,50 @@ class BatchAnnealer:
                 f"init batch has {n_tasks} tasks, arena has {self.ba.n_tasks}"
             )
         if n_tasks < 2 or (self.ba.edges.size == 0 and self.ba.avail.size == 0):
+            self.accepted = np.zeros(n_chains, dtype=np.int64)
             return P0.copy()  # nothing a swap could improve
-        ii, jj = swap_proposals(n_tasks, steps, n_chains, seed)
-        thresh = np.linspace(float(t0), 0.0, steps)
-        used0 = self.ba.used(P0)
-        # Ambient observability: a live MetricsHub gets acceptance counts
-        # and a best-so-far curve; with NULL_HUB (the default) ``rec`` is
-        # None and every recording site is skipped.
         hub = get_hub()
-        rec = _AnnealObs(hub, self.ba, objective, tm) if hub.enabled else None
         # "pallas" selects the fused evaluator in evaluate_batch/
         # throughput_batch; the annealer's hot loop is the fused multi-swap
         # scan either way, so it shares the jax path (bit-identical chains).
         use_jax = self.backend in ("jax", "pallas")
-        if objective == "throughput":
-            if use_jax:
-                return self._run_jax_tp(
-                    P0, used0, ii, jj, thresh, tm, multi_swap, rec
-                )
-            return self._run_numpy_tp(P0, used0, ii, jj, thresh, tm, rec)
+        with hub.span("anneal.dispatch"):
+            ii, jj = swap_proposals(n_tasks, steps, n_chains, seed)
+            thresh = np.linspace(float(t0), 0.0, steps)
+            used0 = self.ba.used(P0)
+            if objective == "throughput":
+                if use_jax:
+                    P, acc = self._run_jax_tp(P0, used0, ii, jj, thresh, tm, multi_swap)
+                else:
+                    P, acc = self._run_numpy_tp(P0, used0, ii, jj, thresh, tm)
+            elif use_jax:
+                P, acc = self._run_jax(P0, used0, ii, jj, thresh, multi_swap)
+            else:
+                P, acc = self._run_numpy(P0, used0, ii, jj, thresh)
         if use_jax:
-            return self._run_jax(P0, used0, ii, jj, thresh, multi_swap, rec)
-        return self._run_numpy(P0, used0, ii, jj, thresh, rec)
+            P = fetch(P, "anneal")
+        #: Per-chain accepted swaps of the last run, as the run left them
+        #: (on the device for the jax path): read through accepted_total.
+        self.accepted = acc
+        # Ambient observability: a live MetricsHub counts proposals and
+        # accepted swaps; the device program is the same with or without it.
+        if hub.enabled:
+            hub.counter("search.proposals").inc(steps * n_chains)
+            hub.counter("search.accepted").inc(self.accepted_total())
+        return P.astype(np.intp)
+
+    def accepted_total(self) -> int:
+        """Accepted swaps of the last :meth:`run`, over all its chains
+        (copies the carried per-chain counts from the device)."""
+        return int(np.asarray(self.accepted).sum(dtype=np.int64))
 
     # -- numpy fallback --------------------------------------------------------
-    def _run_numpy(self, P0, used0, ii, jj, thresh, rec=None) -> np.ndarray:
+    def _run_numpy(self, P0, used0, ii, jj, thresh):
         ba = self.ba
         P = P0.astype(np.intp, copy=True)
         used = used0.copy()
         bidx = np.arange(P.shape[0])
         acc = np.zeros(P.shape[0], dtype=np.int64)
-        marks = _curve_marks(ii.shape[0], 1) if rec is not None else []
-        nm = 0
         mb, mc = ba.move_base, ba.move_cost
         for s in range(ii.shape[0]):
             i, j = ii[s], jj[s]
@@ -267,23 +200,16 @@ class BatchAnnealer:
             np.add.at(used, (bidx, na), du)
             np.add.at(used, (bidx, nb), -du)
             acc += accept
-            if rec is not None and nm < len(marks) and s + 1 == marks[nm]:
-                rec.point(s + 1, P)
-                nm += 1
-        if rec is not None:
-            rec.finish(acc, ii.shape[0])
-        return P
+        return P, acc
 
     # -- numpy fallback, throughput objective ----------------------------------
-    def _run_numpy_tp(self, P0, used0, ii, jj, thresh, tm, rec=None) -> np.ndarray:
+    def _run_numpy_tp(self, P0, used0, ii, jj, thresh, tm):
         ba = self.ba
         P = P0.astype(np.intp, copy=True)
         used = used0.copy()
         B = P.shape[0]
         bidx = np.arange(B)
         acc = np.zeros(B, dtype=np.int64)
-        marks = _curve_marks(ii.shape[0], 1) if rec is not None else []
-        nm = 0
         mb, mc = ba.move_base, ba.move_cost
         cpu_load, mem_used, egress, ingress, rack_up, ack_num = aggregates_numpy(
             ba, tm, P
@@ -362,15 +288,10 @@ class BatchAnnealer:
             ack_num = np.where(w, an, ack_num)
             tp = np.where(accept, tp_new, tp)
             acc += accept
-            if rec is not None and nm < len(marks) and s + 1 == marks[nm]:
-                rec.point(s + 1, P, tp=tp)
-                nm += 1
-        if rec is not None:
-            rec.finish(acc, ii.shape[0])
-        return P
+        return P, acc
 
     # -- jax scan, throughput objective ----------------------------------------
-    def _run_jax_tp(self, P0, used0, ii, jj, thresh, tm, k, rec=None) -> np.ndarray:
+    def _run_jax_tp(self, P0, used0, ii, jj, thresh, tm, k):
         ba = self.ba
         state = aggregates_numpy(ba, tm, P0.astype(np.intp))
         mb, mc = ba.move_arrays()
@@ -385,46 +306,30 @@ class BatchAnnealer:
         )
         P, used = P0.astype(np.int32), used0
         acc = np.zeros(P0.shape[0], dtype=np.int32)
-        steps = ii.shape[0]
-        marks = _curve_marks(steps, min(k, steps)) if rec is not None else None
         with x64():
-            for lo, hi, kk in _swap_blocks(steps, k):
-                # Curve marks only split the scan at full-carry boundaries,
-                # which is bit-identical to the unsplit scan by contract.
-                for mlo, mhi in _mark_segments(lo, hi, marks):
-                    P, used, state, acc = _jax_anneal_tp_fn(tm.ack, kk)(
-                        *model_args, P, used, state, acc,
-                        _rows(ii, mlo, mhi, kk), _rows(jj, mlo, mhi, kk),
-                        thresh[mlo:mhi].reshape(-1, kk),
-                    )
-                    if rec is not None:
-                        rec.point(mhi, np.asarray(P), state=state)
-        if rec is not None:
-            rec.finish(np.asarray(acc), steps)
-        return np.asarray(P).astype(np.intp)
+            for lo, hi, kk in _swap_blocks(ii.shape[0], k):
+                P, used, state, acc = _jax_anneal_tp_fn(tm.ack, kk)(
+                    *model_args, P, used, state, acc,
+                    _rows(ii, lo, hi, kk), _rows(jj, lo, hi, kk),
+                    thresh[lo:hi].reshape(-1, kk),
+                )
+        return P, acc
 
     # -- jax scan --------------------------------------------------------------
-    def _run_jax(self, P0, used0, ii, jj, thresh, k, rec=None) -> np.ndarray:
+    def _run_jax(self, P0, used0, ii, jj, thresh, k):
         ba = self.ba
         P, used = P0.astype(np.int32), used0
         acc = np.zeros(P0.shape[0], dtype=np.int32)
-        steps = ii.shape[0]
         mb, mc = ba.move_arrays()
-        marks = _curve_marks(steps, min(k, steps)) if rec is not None else None
         with x64():
-            for lo, hi, kk in _swap_blocks(steps, k):
-                for mlo, mhi in _mark_segments(lo, hi, marks):
-                    P, used, acc = _jax_anneal_fn(kk)(
-                        ba.net, ba.avail, ba.hard_demand, ba.adj, ba.adj_mask,
-                        mb.astype(np.int32), mc, P, used, acc,
-                        _rows(ii, mlo, mhi, kk), _rows(jj, mlo, mhi, kk),
-                        thresh[mlo:mhi].reshape(-1, kk),
-                    )
-                    if rec is not None:
-                        rec.point(mhi, np.asarray(P))
-        if rec is not None:
-            rec.finish(np.asarray(acc), steps)
-        return np.asarray(P).astype(np.intp)
+            for lo, hi, kk in _swap_blocks(ii.shape[0], k):
+                P, used, acc = _jax_anneal_fn(kk)(
+                    ba.net, ba.avail, ba.hard_demand, ba.adj, ba.adj_mask,
+                    mb.astype(np.int32), mc, P, used, acc,
+                    _rows(ii, lo, hi, kk), _rows(jj, lo, hi, kk),
+                    thresh[lo:hi].reshape(-1, kk),
+                )
+        return P, acc
 
 
 def _swap_blocks(steps: int, k: int):
@@ -482,8 +387,8 @@ def _jax_anneal_fn(k: int):
             P = P.at[bidx, j].set(jnp.where(accept, na, nb))
             du = jnp.where(accept[:, None], dj - di, 0.0)
             used = used.at[bidx, na].add(du).at[bidx, nb].add(-du)
-            # Pure integer side-channel for the per-chain acceptance-rate
-            # telemetry — no float path reads it, so chains are unchanged.
+            # Pure integer side-channel for the accepted-swap counts — no
+            # float path reads it, so chains are unchanged.
             return P, used, acc + accept.astype(jnp.int32)
 
         def step(carry, xs):

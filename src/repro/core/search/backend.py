@@ -18,6 +18,8 @@ import os
 from pathlib import Path
 from typing import Iterator, Optional, Tuple
 
+from ...obs import get_hub
+
 #: Availability is probed without importing: jax's ~1 s import cost must not
 #: tax every ``import repro.core`` (the search registers eagerly there); the
 #: actual module import is deferred to the first jax-backend call.
@@ -84,6 +86,18 @@ def jax_modules():
     import jax.numpy as jnp
 
     return jax, jnp
+
+
+def fetch(x, what: str):
+    """Wait for the device result ``x`` (an array or a tuple of them), then
+    copy it to the host as numpy, each step in a span of its own:
+    ``device.wait`` and ``device.fetch``, labelled ``what``."""
+    jax, _ = jax_modules()
+    hub = get_hub()
+    with hub.span("device.wait", what=what):
+        jax.block_until_ready(x)
+    with hub.span("device.fetch", what=what):
+        return jax.device_get(x)
 
 
 @contextlib.contextmanager

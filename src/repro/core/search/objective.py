@@ -31,7 +31,7 @@ import numpy as np
 # same constant the sequential annealer uses (re-exported for the search),
 # so accept thresholds mean the same thing in both engines.
 from ..engine.annealing import OVERLOAD_PENALTY
-from .backend import chunk_ranges, jax_modules, resolve_backend, x64
+from .backend import chunk_ranges, fetch, jax_modules, resolve_backend, x64
 from .batch import BatchArena
 
 
@@ -114,13 +114,16 @@ def _evaluate_jax(ba: BatchArena, P: np.ndarray, chunk: int) -> BatchEval:
         # set, so a huge batch never materializes one (B, E) intermediate.
         # At most two compiled shapes per batch size (full chunk + tail).
         for lo, hi in chunk_ranges(B, chunk):
-            n, v, d = fn(
-                ba.net, ba.avail, ba.hard_demand, ba.alive, ba.edges,
-                mb, mc, P[lo:hi],
+            n, v, d = fetch(
+                fn(
+                    ba.net, ba.avail, ba.hard_demand, ba.alive, ba.edges,
+                    mb, mc, P[lo:hi],
+                ),
+                "score",
             )
-            net[lo:hi] = np.asarray(n, dtype=np.float64)
-            viol[lo:hi] = np.asarray(v, dtype=np.float64)
-            dead[lo:hi] = np.asarray(d, dtype=np.int64)
+            net[lo:hi] = n
+            viol[lo:hi] = v
+            dead[lo:hi] = d
     return BatchEval(net=net, violation=viol, dead=dead)
 
 

@@ -257,9 +257,9 @@ class SearchScheduler(Scheduler):
             placements=placements,
             unassigned=list(seed_assignment.unassigned),
         )
-        recovered = False
+        P = None
         if len(placements) >= 2:
-            with hub.span("search.compile") as sp:
+            with hub.span("search.inits") as sp:
                 ba = BatchArena.from_arena(
                     arena, topology, placements, avail0=avail0
                 )
@@ -286,10 +286,13 @@ class SearchScheduler(Scheduler):
                     backend=self.backend,
                     multi_swap=self.multi_swap,
                 )
-                P = BatchAnnealer(ba, backend=self.backend).run(
+                annealer = BatchAnnealer(ba, backend=self.backend)
+                P = annealer.run(
                     P0, steps, self.seed, objective=self.objective, tm=tm,
                     multi_swap=self.multi_swap,
                 )
+                if sp.recording:
+                    sp.set(accepted=annealer.accepted_total())
             with hub.span("search.evaluate"):
                 result = evaluate_batch(
                     ba, P, backend=self.backend, throughput_model=tm
@@ -297,7 +300,9 @@ class SearchScheduler(Scheduler):
                 greedy_eval = evaluate_batch(
                     ba, greedy_row, backend=self.backend, throughput_model=tm
                 )
-            if self.objective == "throughput":
+        improved = recovered = False
+        with hub.span("search.pick"):
+            if P is not None and self.objective == "throughput":
                 candidate = self._pick_throughput_candidate(
                     ba, P, result, greedy_eval
                 )
@@ -314,18 +319,21 @@ class SearchScheduler(Scheduler):
                         self._place_unassigned(arena, avail0, topology, trial)
                     if self._simulated_no_worse(topology, cluster, trial, out):
                         out = trial
-                        recovered = True
-            else:
+                        improved = recovered = True
+            elif P is not None:
                 cand = np.where(result.feasible, result.net, np.inf)
                 best = int(np.argmin(cand))  # ties → lowest chain index
                 if np.isfinite(cand[best]) and cand[best] < greedy_eval.net[0]:
                     out.placements = ba.decode(P[best])
-        if out.unassigned and not recovered:
-            # The chosen candidate may have consolidated demand greedy
-            # fragmented — re-attempt the stranded tasks against its
-            # residual budget.
-            self._place_unassigned(arena, avail0, topology, out)
-        span.set(placed=len(out.placements), unassigned=len(out.unassigned))
+                    improved = True
+            if out.unassigned and not recovered:
+                # The chosen candidate may have consolidated demand greedy
+                # fragmented — re-attempt the stranded tasks against its
+                # residual budget.
+                self._place_unassigned(arena, avail0, topology, out)
+        span.set(
+            placed=len(out.placements), unassigned=len(out.unassigned), improved=improved
+        )
         return out
 
     def _pick_throughput_candidate(

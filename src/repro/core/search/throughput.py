@@ -52,7 +52,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from .backend import chunk_ranges, jax_modules, resolve_backend, x64
+from .backend import chunk_ranges, fetch, jax_modules, resolve_backend, x64
 from .batch import BatchArena
 
 _EPS = 1e-12
@@ -668,7 +668,7 @@ def _throughput_jax(ba: BatchArena, tm: ThroughputModel, P: np.ndarray, chunk: i
         # time instead of a monolithic (B, E) one (same contract as
         # ``evaluate_batch``; at most two compiled shapes per batch size).
         for lo, hi in chunk_ranges(P.shape[0], chunk):
-            out[lo:hi] = np.asarray(
+            out[lo:hi] = fetch(
                 fn(
                     P[lo:hi], tm.task_cpu, tm.task_mem,
                     tm.cpu_cap, tm.mem_cap, tm.nic_cap, tm.rack_cap,
@@ -677,7 +677,7 @@ def _throughput_jax(ba: BatchArena, tm: ThroughputModel, P: np.ndarray, chunk: i
                     tm.edge_local, tm.pair_key, tm.combo_ce, tm.local_num,
                     tm.thrash_factor, tm.source_bound, tm.sink_rate,
                 ),
-                dtype=np.float64,
+                "score",
             )
     return out
 

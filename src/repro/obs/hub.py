@@ -15,12 +15,25 @@ so the control plane can instrument everything it constructs with one
 ambient hub is :data:`NULL_HUB`, a disabled hub whose accessors hand out
 inert singletons and retain **zero** state -- the disabled path is a
 couple of attribute checks, so hot loops keep their benchmarked numbers.
+
+The jax profiler
+----------------
+While a jax profiler session captures (``TraceAnnotation.is_enabled()``),
+every span -- ``NULL_HUB``'s included -- also opens a
+``jax.profiler.TraceAnnotation`` of its name, which puts it in the trace's
+host plane on the device trace's clock, and closes into the bounded
+process-wide profile log that :func:`profiled_spans` reads.  The log keeps
+its own open-span stack, so parent links hold across hubs.  With no
+capture running, ``NULL_HUB.span`` still hands out ``NULL_SPAN``; jax is
+looked up only once something else has imported it.
 """
 
 from __future__ import annotations
 
+import collections
 import json
-from typing import Dict, Iterable, List, Optional, Tuple
+import sys
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from . import clock
 from .metrics import (
@@ -73,6 +86,9 @@ class _NullSpan:
 
     __slots__ = ()
 
+    #: Whether ``set`` keeps what it is given (callers skip costly meta).
+    recording = False
+
     def __enter__(self) -> "_NullSpan":
         return self
 
@@ -86,6 +102,97 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class ProfiledSpan(NamedTuple):
+    """One closed span of the profile log.  ``seq`` and ``parent`` number
+    the log's own spans (the enclosing open span's ``seq``, across hubs)."""
+
+    seq: int
+    parent: Optional[int]
+    name: str
+    labels: Dict[str, object]
+    meta: Dict[str, object]
+    wall_s: float
+
+
+#: Closed spans the profile log keeps; the oldest are dropped beyond it.
+PROFILE_LOG_SPANS = 65_536
+
+
+class _ProfileLog:
+    """Spans taken while the jax profiler captures, in closing order.  Like
+    a hub's own open-span stack, its stack assumes spans nest on one thread."""
+
+    def __init__(self) -> None:
+        self.closed: "collections.deque[ProfiledSpan]" = collections.deque(
+            maxlen=PROFILE_LOG_SPANS
+        )
+        self.stack: List[int] = []
+        self.seq = 0
+        #: ``jax.profiler.TraceAnnotation``, once jax has been imported.
+        self.annotation = None
+
+    def capturing(self) -> bool:
+        """True while a jax profiler session captures.  jax is never
+        imported from here: without it no session can exist."""
+        annotation = self.annotation
+        if annotation is None:
+            if "jax" not in sys.modules:
+                return False
+            from jax.profiler import TraceAnnotation
+
+            annotation = self.annotation = TraceAnnotation
+        return annotation.is_enabled()
+
+
+_PROFILE_LOG = _ProfileLog()
+
+
+def profiled_spans() -> Tuple[ProfiledSpan, ...]:
+    """The profile log's closed spans, oldest first (children close, and so
+    appear, before their parents)."""
+    return tuple(_PROFILE_LOG.closed)
+
+
+class _ProfileSpan:
+    """A span opened while the profiler captures: a ``TraceAnnotation`` of
+    the same name, timed into the profile log.  ``NULL_HUB`` hands these
+    out directly; an enabled hub's :class:`Span` opens one beside itself."""
+
+    __slots__ = ("name", "labels", "meta", "seq", "parent", "_ann", "_t0")
+
+    recording = True
+
+    def __init__(self, name: str, labels: Dict[str, object], meta=None) -> None:
+        self.name = name
+        self.labels = labels
+        self.meta: Dict[str, object] = {} if meta is None else meta
+
+    def __enter__(self) -> "_ProfileSpan":
+        log = _PROFILE_LOG
+        self.seq = log.seq
+        log.seq += 1
+        self.parent = log.stack[-1] if log.stack else None
+        log.stack.append(self.seq)
+        self._ann = log.annotation(self.name)
+        self._ann.__enter__()
+        self._t0 = clock.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        wall_s = clock.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        log = _PROFILE_LOG
+        log.stack.pop()
+        log.closed.append(
+            ProfiledSpan(self.seq, self.parent, self.name, self.labels, self.meta, wall_s)
+        )
+        return False
+
+    def set(self, **meta) -> "_ProfileSpan":
+        self.meta.update(meta)
+        return self
+
+
 class Span:
     """One trace event: hub-assigned ``seq`` + parent link + typed meta.
 
@@ -95,7 +202,11 @@ class Span:
     ``include_wall=True``.
     """
 
-    __slots__ = ("name", "labels", "seq", "parent", "meta", "wall_s", "_hub", "_t0")
+    __slots__ = (
+        "name", "labels", "seq", "parent", "meta", "wall_s", "_hub", "_t0", "_prof",
+    )
+
+    recording = True
 
     def __init__(self, hub: "MetricsHub", name: str, labels: Dict[str, object]):
         self._hub = hub
@@ -106,6 +217,7 @@ class Span:
         self.meta: Dict[str, object] = {}
         self.wall_s: float = 0.0
         self._t0 = 0.0
+        self._prof: Optional[_ProfileSpan] = None
 
     def __enter__(self) -> "Span":
         hub = self._hub
@@ -114,11 +226,17 @@ class Span:
         self.parent = hub._stack[-1].seq if hub._stack else None
         hub._stack.append(self)
         hub._spans.append(self)
+        if _PROFILE_LOG.capturing():
+            # Shares ``meta``, so the log sees what ``set`` attaches.
+            self._prof = _ProfileSpan(self.name, self.labels, self.meta).__enter__()
         self._t0 = clock.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.wall_s = clock.perf_counter() - self._t0
+        if self._prof is not None:
+            self._prof.__exit__(*exc)
+            self._prof = None
         self._hub._stack.pop()
         return False
 
@@ -208,7 +326,7 @@ class MetricsHub:
     # -- spans --------------------------------------------------------------
     def span(self, name: str, **labels):
         if not self.enabled:
-            return NULL_SPAN
+            return _ProfileSpan(name, labels) if _PROFILE_LOG.capturing() else NULL_SPAN
         return Span(self, name, labels)
 
     # -- export -------------------------------------------------------------

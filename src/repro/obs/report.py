@@ -115,6 +115,14 @@ def summarize(path: str, top: int = 5, out=None) -> None:
     if spans:
         print(f"\n## spans ({len(spans)})", file=out)
         by_seq = {rec["seq"]: rec for rec in spans}
+        # The annealer's wait on its scan, by the anneal span's seq.
+        scan_wall = {
+            rec["parent"]: rec["wall_s"]
+            for rec in spans
+            if rec["name"] == "device.wait"
+            and rec.get("labels", {}).get("what") == "anneal"
+            and rec.get("wall_s") is not None
+        }
         for rec in spans:
             indent = "  " * _span_depth(rec, by_seq)
             meta = rec.get("meta", {})
@@ -126,9 +134,12 @@ def summarize(path: str, top: int = 5, out=None) -> None:
             wall = rec.get("wall_s")
             if wall is not None:
                 parts.append(f"wall={wall * 1e3:.2f}ms")
-                # swaps/s: the annealer span carries its proposal count.
-                if isinstance(meta.get("proposals"), (int, float)) and wall > 0:
-                    parts.append(f"swaps_per_s={meta['proposals'] / wall:.3g}")
+                # swaps/s: the annealer span carries its proposal count; the
+                # scan's time is its device.wait child where it has one (the
+                # span's own wall adds dispatch and fetch).
+                scan = scan_wall.get(rec["seq"], wall)
+                if isinstance(meta.get("proposals"), (int, float)) and scan > 0:
+                    parts.append(f"swaps_per_s={meta['proposals'] / scan:.3g}")
             print("  " + " ".join(parts), file=out)
 
 

@@ -16,7 +16,6 @@ Covers the four contracts the plane ships with:
 from __future__ import annotations
 
 import json
-import math
 
 import pytest
 
@@ -329,22 +328,155 @@ def test_search_placements_invariant_under_hub(backend):
     observed = run(hub)
     assert observed.placements == bare.placements
     names = {r["name"] for r in hub.records()}
-    assert {"search.best_objective", "search.chain_accept_rate",
-            "search.accept_rate", "search.proposals", "search.accepted",
-            "search.schedule", "search.anneal"} <= names
-    # Acceptance rates are probabilities; the curve is monotone non-increasing
-    # for the netcost objective (best-so-far).
-    (_, gauge), = hub.find("gauge", "search.accept_rate")
-    assert 0.0 <= gauge.value <= 1.0
-    (_, curve), = hub.find("series", "search.best_objective")
-    values = [p[1] for p in curve.points]
-    assert values == sorted(values, reverse=True) or all(
-        not math.isnan(v) for v in values
-    )
+    assert {"search.proposals", "search.accepted", "search.schedule",
+            "search.seed", "search.inits", "search.anneal", "anneal.dispatch",
+            "search.evaluate", "search.pick"} <= names
+    # The counters count what the scan did: every chain's proposals, and
+    # the accepted swaps the anneal span reads from the carried counts.
+    (_, proposals), = hub.find("counter", "search.proposals")
+    (_, accepted), = hub.find("counter", "search.accepted")
+    assert proposals.value == 4 * 40
+    assert 0 < accepted.value <= proposals.value
+    (anneal,) = [r for r in hub.records() if r["name"] == "search.anneal"]
+    assert anneal["meta"]["accepted"] == accepted.value
     # Telemetry itself is deterministic.
     hub2 = MetricsHub()
     run(hub2)
     assert hub2.to_jsonl() == hub.to_jsonl()
+
+
+def _tiny_search(backend="jax"):
+    cluster = Cluster.homogeneous(racks=2, nodes_per_rack=4, cpu=400.0, memory_mb=4096.0)
+    sched = get_scheduler(
+        "rstorm-search", seed=5, n_chains=4, steps=40, multi_swap=4, backend=backend
+    )
+    return sched.schedule(T.linear(), cluster, commit=False)
+
+
+@pytest.mark.skipif(not _has_jax(), reason="jax not installed")
+def test_enabled_hub_runs_the_same_scan_calls(monkeypatch):
+    from repro.core.search import anneal
+
+    calls = []
+    real = anneal._jax_anneal_fn
+
+    def counted(k):
+        fn = real(k)
+
+        def call(*args):
+            calls.append(k)
+            return fn(*args)
+
+        return call
+
+    monkeypatch.setattr(anneal, "_jax_anneal_fn", counted)
+    bare = _tiny_search()
+    n_bare = len(calls)
+    with MetricsHub().activate():
+        observed = _tiny_search()
+    assert observed.placements == bare.placements
+    # 40 proposals at multi_swap=4: one whole scan, never split by the hub.
+    assert n_bare == 1
+    assert len(calls) - n_bare == n_bare
+
+
+# --------------------------------------------------------------------------
+# the jax profiler: spans reach the trace while it captures, and only then
+# --------------------------------------------------------------------------
+
+#: Every span a jax-backend search decision opens.
+SEARCH_SPANS = (
+    "search.schedule", "search.seed", "search.inits", "search.anneal",
+    "anneal.dispatch", "device.wait", "device.fetch", "search.evaluate",
+    "search.pick",
+)
+
+
+def test_null_hub_keeps_no_state_when_no_profiler_captures():
+    from repro.obs import profiled_spans
+
+    before = profiled_spans()
+    assert NULL_HUB.span("s") is NULL_SPAN
+    assert _tiny_search(backend="numpy").placements
+    if _has_jax():
+        assert _tiny_search().placements
+    assert NULL_HUB.records() == [] and NULL_HUB._stack == []
+    assert profiled_spans() == before
+
+
+def _host_events(trace_dir, names):
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in names:
+                        out.setdefault(e.name, []).append(
+                            (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                        )
+    return {n: sorted(v) for n, v in out.items()}
+
+
+@pytest.mark.skipif(not _has_jax(), reason="jax not installed")
+def test_spans_reach_the_profilers_host_plane(tmp_path):
+    import jax
+
+    from repro.obs import profiled_spans
+
+    _tiny_search()  # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        with jax.profiler.TraceAnnotation("decision"):
+            # An enabled hub's spans join the same log: parent links hold
+            # across hubs.
+            with NULL_HUB.span("outer") as outer:
+                with MetricsHub().span("inner") as inner:
+                    pass
+            _tiny_search()
+    logged = profiled_spans()
+    by_seq = {sp.seq: sp for sp in logged}
+    (root,) = [sp for sp in logged if sp.name == "search.schedule"][-1:]
+    tree = [root]
+    for sp in reversed(logged):  # children close before their parents
+        if sp.parent in {t.seq for t in tree}:
+            tree.append(sp)
+    assert {sp.name for sp in tree} == set(SEARCH_SPANS)
+    assert {sp.labels.get("what") for sp in tree if sp.name == "device.wait"} == {
+        "anneal", "score"
+    }
+    assert root.meta["improved"] in (True, False)
+    (anneal_span,) = [sp for sp in tree if sp.name == "search.anneal"]
+    assert 0 < anneal_span.meta["accepted"] <= anneal_span.meta["proposals"]
+    (inner_logged,) = [sp for sp in logged if sp.name == "inner"][-1:]
+    assert by_seq[inner_logged.parent].name == "outer"
+    assert outer.recording and inner.wall_s >= 0.0
+
+    events = _host_events(tmp_path, set(SEARCH_SPANS) | {"decision", "outer", "inner"})
+    ((d0, d1),) = events["decision"]
+    # Each name's trace events, in start order, are its logged spans in
+    # open (seq) order: durations agree to within 1 ms, nesting holds.
+    interval = {}
+    for name in (*SEARCH_SPANS, "outer", "inner"):
+        spans = sorted(
+            (sp for sp in tree + [inner_logged, by_seq[inner_logged.parent]] if sp.name == name),
+            key=lambda sp: sp.seq,
+        )
+        assert len(events[name]) == len(spans), name
+        for sp, (s, e) in zip(spans, events[name]):
+            assert d0 <= s <= e <= d1
+            assert abs((e - s) - sp.wall_s) < 1e-3, name
+            interval[sp.seq] = (s, e)
+    for seq, (s, e) in interval.items():
+        parent = by_seq[seq].parent
+        if parent in interval:
+            ps, pe = interval[parent]
+            assert ps <= s <= e <= pe
 
 
 # --------------------------------------------------------------------------
@@ -393,6 +525,26 @@ def test_report_cli_summarize_and_self_diff(tmp_path, capsys):
     assert "top-3 hot nodes" in out
     assert report_main(["diff", str(p), str(p)]) == 0
     assert "identical telemetry" in capsys.readouterr().out
+
+
+def test_report_swaps_per_s_reads_the_scans_wait(tmp_path, capsys):
+    """swaps/s divides the annealer's proposals by its device.wait child's
+    wall time, which leaves out dispatch and fetch; the span's own wall is
+    the fallback where it has no such child."""
+    spans = [
+        {"kind": "span", "name": "search.anneal", "labels": {}, "seq": 0,
+         "parent": None, "meta": {"proposals": 1000}, "wall_s": 1.0},
+        {"kind": "span", "name": "device.wait", "labels": {"what": "anneal"},
+         "seq": 1, "parent": 0, "meta": {}, "wall_s": 0.5},
+        {"kind": "span", "name": "search.anneal", "labels": {}, "seq": 2,
+         "parent": None, "meta": {"proposals": 1000}, "wall_s": 0.25},
+    ]
+    p = tmp_path / "spans.jsonl"
+    p.write_text("".join(json.dumps(r) + "\n" for r in spans))
+    assert report_main(["summarize", str(p)]) == 0
+    out = capsys.readouterr().out
+    assert "swaps_per_s=2e+03" in out and "swaps_per_s=4e+03" in out
+    assert "swaps_per_s=1e+03" not in out
 
 
 def test_report_cli_diff_flags_changed_run(tmp_path, capsys):
