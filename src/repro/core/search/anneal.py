@@ -3,7 +3,8 @@
 B independent chains run pairwise-swap local search *simultaneously*: each
 step proposes one swap per chain, scores it with the same O(degree)
 incremental delta the sequential ``SwapAnnealer`` uses
-(:func:`repro.core.engine.arena.swap_network_delta`), and accepts it under a
+(:func:`repro.core.engine.arena.swap_network_delta`; the jax netcost scan
+sums it by node, :func:`histogram_network_delta`), and accepts it under a
 threshold-accepting schedule (Dueck & Scheuer's deterministic cousin of
 simulated annealing): a swap is accepted iff
 
@@ -64,6 +65,35 @@ def move_delta(move_cost, move_base, i, j, na, nb, xp=np):
     return ci * (
         (nb != bi).astype(xp.float64) - (na != bi).astype(xp.float64)
     ) + cj * ((na != bj).astype(xp.float64) - (nb != bj).astype(xp.float64))
+
+
+def histogram_network_delta(net, na, nb, counts, m_ab, xp=np):
+    """Δ(network cost) of swapping the nodes ``na``/``nb`` (B,) of two tasks
+    i and j, from ``counts`` (B, N): i's neighbours on each node less j's.
+
+    The same sum as :func:`swap_network_delta`, regrouped by node:
+    Σₙ counts[b, n] · (net[nb, n] − net[na, n]) − m_ab · corr.  It reads
+    two rows of ``net`` per chain where that reads two entries per
+    neighbour, one at a time.  The result is the shared delta's to the bit
+    only where every sum is exact in any order, which
+    :func:`sums_exactly` checks."""
+    rows = net[nb] - net[na]
+    corr = net[na, na] + net[nb, nb] - 2.0 * net[na, nb]
+    return (counts * rows).sum(axis=-1) - m_ab * corr
+
+
+def sums_exactly(net, terms: int) -> bool:
+    """Whether every sum of up to ``terms`` entries of ``net``, each with
+    a sign, is exact in float64 whatever the order: every entry is a whole
+    multiple of one power of two 2**-s, and ``terms`` times the largest
+    entry stays below 2**53 of those.  The rack distances (0.5, 1, 2) are;
+    a table holding 0.1 is not, past two terms."""
+    top = float(np.abs(net).max())
+    for s in range(1075):
+        scaled = np.ldexp(net, s)
+        if np.array_equal(scaled, np.round(scaled)):
+            return top * terms * 2.0**s < 2.0**53
+    return False
 
 
 def swap_proposals(
@@ -317,19 +347,40 @@ class BatchAnnealer:
 
     # -- jax scan --------------------------------------------------------------
     def _run_jax(self, P0, used0, ii, jj, thresh, k):
-        ba = self.ba
         P, used = P0.astype(np.int32), used0
         acc = np.zeros(P0.shape[0], dtype=np.int32)
-        mb, mc = ba.move_arrays()
+        tables = scan_tables(self.ba)
         with x64():
             for lo, hi, kk in _swap_blocks(ii.shape[0], k):
                 P, used, acc = _jax_anneal_fn(kk)(
-                    ba.net, ba.avail, ba.hard_demand, ba.adj, ba.adj_mask,
-                    mb.astype(np.int32), mc, P, used, acc,
+                    *tables, P, used, acc,
                     _rows(ii, lo, hi, kk), _rows(jj, lo, hi, kk),
                     thresh[lo:hi].reshape(-1, kk),
                 )
         return P, acc
+
+
+def scan_tables(ba: BatchArena) -> tuple:
+    """The arena's tables as the netcost scan reads them: ``(net, avail,
+    hard_demand, adj, move_base, move_cost)``.
+
+    Indices are int32, so no 64-bit integer is left in the scan (the chip
+    gathers an int64 as two u32 halves); the padding mask is ``adj >= 0``
+    inside it.  The scan sums the network distances by node
+    (:func:`histogram_network_delta`), in another order than the numpy
+    path, so it refuses a table whose sums that order could round: a
+    delta sums at most 8 · (``max_deg`` + 1) distances."""
+    if not sums_exactly(ba.net, 8 * (ba.adj.shape[1] + 1)):
+        raise ValueError(
+            "the jax netcost scan needs network distances whose sums are "
+            "exact in float64 (multiples of one power of two); run this "
+            "table with backend='numpy'"
+        )
+    mb, mc = ba.move_arrays()
+    return (
+        ba.net, ba.avail, ba.hard_demand, ba.adj.astype(np.int32),
+        mb.astype(np.int32), mc,
+    )
 
 
 def _swap_blocks(steps: int, k: int):
@@ -358,24 +409,34 @@ def _jax_anneal_fn(k: int):
     at trace time), so the chain is bit-identical to k=1 while the scan —
     and its per-step dispatch/carry overhead — shrinks k×.  Returns the
     full carry so a tail call can chain.  One cached callable per k serves
-    every arena/batch size (jit re-specializes on array shapes)."""
+    every arena/batch size (jit re-specializes on array shapes).
+
+    Takes :func:`scan_tables`'s tables.  The net part of the delta is
+    :func:`histogram_network_delta`: the chip gathers an array one element
+    at a time but a table row at once, so it reads each neighbour's node
+    and two rows of ``net``, not two ``net`` entries per neighbour."""
     jax, jnp = jax_modules()
 
     @jax.jit
     def anneal(
-        net, avail, hard_demand, adj, adj_mask, move_base, move_cost,
+        net, avail, hard_demand, adj, move_base, move_cost,
         P0, used0, acc0, ii, jj, thresh,
     ):
-        bidx = jnp.arange(P0.shape[0])
+        bidx = jnp.arange(P0.shape[0], dtype=jnp.int32)
+        nodes = jnp.arange(net.shape[0], dtype=jnp.int32)
+
+        def on_nodes(P, a):
+            """Adjacency rows (B, max_deg) → each chain's count of those
+            neighbours on each node, (B, N); padding (-1) counts nowhere."""
+            p = jnp.where(a >= 0, P[bidx[:, None], jnp.maximum(a, 0)], -1)
+            return (p[..., None] == nodes).sum(axis=1, dtype=jnp.int32)
 
         def swap(P, used, acc, i, j, th):
             na, nb = P[bidx, i], P[bidx, j]
-            ai, mi = adj[i], adj_mask[i]
-            aj, mj = adj[j], adj_mask[j]
-            pa = P[bidx[:, None], jnp.where(mi, ai, 0)]
-            pb = P[bidx[:, None], jnp.where(mj, aj, 0)]
-            m_ab = ((ai == j[:, None]) & mi).sum(axis=-1)
-            delta = swap_network_delta(net, na, nb, pa, pb, m_ab, mi, mj, xp=jnp)
+            ai, aj = adj[i], adj[j]
+            counts = on_nodes(P, ai) - on_nodes(P, aj)
+            m_ab = (ai == j[:, None]).sum(axis=-1, dtype=jnp.int32)
+            delta = histogram_network_delta(net, na, nb, counts, m_ab, xp=jnp)
             di, dj = hard_demand[i], hard_demand[j]
             delta = delta + OVERLOAD_PENALTY * swap_overload_delta(
                 avail[na], avail[nb], used[bidx, na], used[bidx, nb], di, dj, xp=jnp

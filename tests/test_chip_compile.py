@@ -16,6 +16,8 @@ be read back without one.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -26,7 +28,11 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 import chip_smoke  # noqa: E402
 from repro.api import Nimbus  # noqa: E402
 from repro.core import BatchArena, PlacementArena  # noqa: E402
-from repro.core.search.anneal import _jax_anneal_fn, swap_proposals  # noqa: E402
+from repro.core.search.anneal import (  # noqa: E402
+    _jax_anneal_fn,
+    scan_tables,
+    swap_proposals,
+)
 from repro.core.search.backend import x64  # noqa: E402
 from repro.core.search.kernels.fused_score import (  # noqa: E402
     DEFAULT_BLOCK_B,
@@ -117,22 +123,49 @@ def test_scorer_compiles_for_v5e(flagship, one_chip, no_persistent_cache):
     _compile(_jax_eval_fn(ba.n_nodes), args, one_chip)
 
 
+def _scan_args(ba, chains, steps, k):
+    """The netcost scan's arguments as ``BatchAnnealer._run_jax`` passes
+    them, for ``steps`` proposals fused ``k`` per scan element."""
+    P0 = _batch(ba, chains)
+    ii, jj = swap_proposals(ba.n_tasks, steps, chains, 0)
+    return (
+        *scan_tables(ba), P0.astype(np.int32), ba.used(P0),
+        np.zeros(chains, dtype=np.int32),
+        ii.astype(np.int32).reshape(steps // k, k, chains),
+        jj.astype(np.int32).reshape(steps // k, k, chains),
+        np.linspace(1.0, 0.0, steps).reshape(steps // k, k),
+    )
+
+
 def test_annealer_step_compiles_for_v5e(flagship, one_chip, no_persistent_cache):
     """The netcost ``lax.scan`` annealer, one proposal per scan element."""
     ba, _ = flagship
-    chains, steps = chip_smoke.CHAINS, chip_smoke.STEPS
-    P0 = _batch(ba, chains)
-    ii, jj = swap_proposals(ba.n_tasks, steps, chains, 0)
-    mb, mc = ba.move_arrays()
-    args = (
-        ba.net, ba.avail, ba.hard_demand, ba.adj, ba.adj_mask,
-        mb.astype(np.int32), mc, P0.astype(np.int32), ba.used(P0),
-        np.zeros(chains, dtype=np.int32),
-        ii.astype(np.int32).reshape(steps, 1, chains),
-        jj.astype(np.int32).reshape(steps, 1, chains),
-        np.linspace(1.0, 0.0, steps).reshape(steps, 1),
-    )
+    args = _scan_args(ba, chip_smoke.CHAINS, chip_smoke.STEPS, 1)
     _compile(_jax_anneal_fn(1), args, one_chip)
+
+
+def test_annealer_scan_gathers_for_v5e(flagship, one_chip, no_persistent_cache):
+    """What each swap of the netcost scan gathers, at the plan cell's widths
+    (B = 64 chains, ``max_deg`` = 80) and its fusion (k = 8).
+
+    At first each swap gathered 16 arrays of (B, ``max_deg``): four net
+    entries per neighbour, each in the two float32 halves of an emulated
+    float64; the two adjacency rows in two u32 halves each, being int64;
+    the two mask rows; the two rows of the neighbours' nodes.  Now 4: the
+    adjacency rows and the neighbours' nodes, all int32, with no 64-bit
+    integer left in the program; the net distances come as two rows of
+    the table per chain, from the neighbours' histogram over nodes."""
+    ba, _ = flagship
+    B, k = 64, 8
+    width = ba.adj.shape[1]
+    assert width == 80
+    text = _compile(_jax_anneal_fn(k), _scan_args(ba, B, 2000, k), one_chip).as_text()
+    assert not re.search(r"\b[su]64\[", text)
+    wide = re.findall(
+        rf"=\s*(\w+)\[{B},{width}\]\{{[^}}]*\}}\s+gather\(", text
+    )
+    assert "u32" not in wide and "pred" not in wide
+    assert 0 < len(wide) <= 4 * k, sorted(wide)
 
 
 @pytest.mark.xfail(

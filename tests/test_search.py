@@ -29,7 +29,12 @@ from repro.core import (
 )
 from repro.core.engine import swap_network_delta, swap_overload_delta
 from repro.core.search import BatchAnnealer, HAS_JAX
-from repro.core.search.anneal import swap_proposals
+from repro.core.search.anneal import (
+    histogram_network_delta,
+    scan_tables,
+    sums_exactly,
+    swap_proposals,
+)
 from repro.core.search.throughput import compile_throughput, throughput_batch
 from repro.stream import Simulator, topologies as T
 
@@ -192,19 +197,145 @@ def test_annealer_chains_stay_feasible_from_greedy(backend):
     assert (result.dead == 0).all()
 
 
+def two_chains():
+    """Two chains of unequal parallelism in one topology, as two tenants
+    are: unequal degrees, so the padded adjacency has -1 entries."""
+    t = Topology("two-chains")
+    for name, par in (("a", 7), ("b", 3)):
+        prev = None
+        for i in range(3):
+            c = Component(f"{name}{i}", is_spout=(i == 0), parallelism=par)
+            c.set_memory_load(128.0).set_cpu_load(10.0)
+            t.add_component(c)
+            if prev:
+                t.add_edge(prev, c.id)
+            prev = c.id
+    return t
+
+
+def anneal_case(name):
+    """``(ba, P0, steps, seed, k, t0)`` of one golden case of the netcost
+    annealer, where jax must walk numpy's chains bit for bit."""
+    steps, seed, k, t0 = 200, 13, 1, 2.0
+    if name == "pageload":
+        ba = compile_case(T.pageload, emulab_cluster)[-1]
+        P0 = random_batch(ba, 16, seed=9)
+    elif name == "padded":
+        ba = compile_case(two_chains, emulab_cluster)[-1]
+        assert not ba.adj_mask.all()
+        P0 = random_batch(ba, 8, seed=3)
+    elif name == "neighbours":
+        # 12 tasks, 8 of them adjacent to 8 others: many proposals swap
+        # direct neighbours (m_ab > 0).
+        ba = compile_case(lambda: chain_topology(3, 4), emulab_cluster)[-1]
+        P0 = random_batch(ba, 8, seed=4)
+        ii, jj = swap_proposals(ba.n_tasks, steps, 8, seed)
+        assert (ba.adj[ii] == jj[..., None]).any(axis=-1).mean() > 0.3
+    elif name == "infeasible":
+        # 20 tasks of 128 MB on six 512 MB nodes: random seeds overload.
+        ba = compile_case(
+            lambda: chain_topology(5, 4),
+            lambda: Cluster.homogeneous(racks=2, nodes_per_rack=3, memory_mb=512.0),
+        )[-1]
+        P0 = random_batch(ba, 8, seed=5)
+        assert (evaluate_batch(ba, P0, backend="numpy").violation > 0).any()
+    elif name == "move-costs":
+        ba = compile_case(T.pageload, emulab_cluster)[-1]
+        rng = np.random.Generator(np.random.Philox(6))
+        ba.move_base = np.flatnonzero(ba.alive)[
+            rng.integers(0, ba.alive.sum(), size=ba.n_tasks)
+        ]
+        ba.move_cost = rng.integers(0, 8, size=ba.n_tasks) * 0.25
+        P0 = random_batch(ba, 8, seed=6)
+    elif name == "k8-tail":
+        # 203 = 25 blocks of 8 and a tail of 3 single swaps.
+        ba = compile_case(two_chains, emulab_cluster)[-1]
+        P0 = random_batch(ba, 8, seed=7)
+        steps, k = 203, 8
+    elif name == "one-chain":
+        ba = compile_case(T.pageload, emulab_cluster)[-1]
+        P0 = random_batch(ba, 1, seed=8)
+    elif name == "float64-table":
+        # Distances float32 cannot hold (2**-30 steps), still on one grid
+        # of a power of two, so every sum is exact in any order (the scan
+        # refuses other tables); hill-climbing (t0 = 0) turns on their
+        # last bits.
+        ba = compile_case(T.pageload, emulab_cluster)[-1]
+        n = np.arange(ba.n_nodes)
+        ba.net = ba.net + (np.add.outer(n, n) % 5) * 2.0**-30
+        assert not np.array_equal(ba.net.astype(np.float32), ba.net)
+        P0 = random_batch(ba, 8, seed=10)
+        t0 = 0.0
+    else:
+        raise KeyError(name)
+    return ba, P0, steps, seed, k, t0
+
+
+ANNEAL_CASES = [
+    "pageload", "padded", "neighbours", "infeasible", "move-costs", "k8-tail",
+    "one-chain", "float64-table",
+]
+
+
 @pytest.mark.skipif(not HAS_JAX, reason="jax not installed")
-def test_annealer_backends_golden_equal():
-    topology, cluster, arena, assignment, ba = compile_case(
-        lambda: T.pageload(), lambda: emulab_cluster()
-    )
-    P0 = random_batch(ba, 16, seed=9)
-    a = BatchAnnealer(ba, backend="numpy").run(P0, steps=200, seed=13)
-    b = BatchAnnealer(ba, backend="jax").run(P0, steps=200, seed=13)
+@pytest.mark.parametrize("case", ANNEAL_CASES)
+def test_annealer_backends_golden_equal(case):
+    ba, P0, steps, seed, k, t0 = anneal_case(case)
+    numpy_run, jax_run = BatchAnnealer(ba, backend="numpy"), BatchAnnealer(ba, backend="jax")
+    a = numpy_run.run(P0, steps=steps, seed=seed, t0=t0)
+    b = jax_run.run(P0, steps=steps, seed=seed, t0=t0, multi_swap=k)
     assert (a == b).all()
+    assert np.array_equal(np.asarray(numpy_run.accepted), np.asarray(jax_run.accepted))
+    assert numpy_run.accepted_total() > 0
     ra = evaluate_batch(ba, a, backend="numpy")
     rb = evaluate_batch(ba, b, backend="jax")
     assert (ra.net == rb.net).all()
     assert (ra.violation == rb.violation).all()
+
+
+@pytest.mark.parametrize("case", ["padded", "neighbours", "move-costs", "float64-table"])
+def test_histogram_delta_equals_shared_delta(case):
+    """The jax scan's delta, regrouped by node and read from the table the
+    scan is given, equals the shared per-neighbour delta to the bit."""
+    ba, P, *_ = anneal_case(case)
+    net = scan_tables(ba)[0]
+    bidx = np.arange(P.shape[0])
+    ii, jj = swap_proposals(ba.n_tasks, 40, P.shape[0], seed=1)
+    for i, j in zip(ii, jj):
+        na, nb = P[bidx, i], P[bidx, j]
+        mi, mj = ba.adj_mask[i], ba.adj_mask[j]
+        pa = P[bidx[:, None], np.where(mi, ba.adj[i], 0)]
+        pb = P[bidx[:, None], np.where(mj, ba.adj[j], 0)]
+        m_ab = ((ba.adj[i] == j[:, None]) & mi).sum(axis=-1)
+        counts = np.stack([
+            np.bincount(pa[b][mi[b]], minlength=ba.n_nodes)
+            - np.bincount(pb[b][mj[b]], minlength=ba.n_nodes)
+            for b in bidx
+        ])
+        shared = swap_network_delta(ba.net, na, nb, pa, pb, m_ab, mi, mj)
+        assert np.array_equal(
+            histogram_network_delta(net, na, nb, counts, m_ab), shared
+        )
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="jax not installed")
+def test_scan_refuses_a_net_table_it_cannot_sum_exactly():
+    """The scan sums distances by node, the numpy path by neighbour: with a
+    distance of 0.1 the two orders round apart, so the jax scan refuses
+    that table rather than walk other chains."""
+    ba = compile_case(T.pageload, emulab_cluster)[-1]
+    net, _, _, adj, move_base, _ = scan_tables(ba)
+    assert net is ba.net and adj.dtype == move_base.dtype == np.int32
+    assert ba.adj.dtype == np.intp  # the arena as it was
+    assert sums_exactly(ba.net, 8 * (ba.adj.shape[1] + 1))
+    # 0.1 is an odd multiple of 2**-55: two of it sum exactly, sixteen may not.
+    assert sums_exactly(np.array([[0.1]]), 2)
+    assert not sums_exactly(np.array([[0.1]]), 16)
+    assert not sums_exactly(np.array([[1.0, 2.0**-60]]), 2)
+    ba.net = ba.net.copy()
+    ba.net[0, 1] = ba.net[1, 0] = 0.1
+    with pytest.raises(ValueError, match="exact"):
+        BatchAnnealer(ba, backend="jax").run(random_batch(ba, 2, seed=1), steps=4, seed=1)
 
 
 # -- the registered scheduler -----------------------------------------------------

@@ -31,7 +31,13 @@ from repro.core.search.kernels import DEFAULT_BLOCK_B, fused_score
 from repro.core.search.throughput import compile_throughput, throughput_batch
 from repro.stream import topologies as T
 
-from tests.test_search import compile_case, emulab_cluster, random_batch
+from tests.test_search import (
+    ANNEAL_CASES,
+    anneal_case,
+    compile_case,
+    emulab_cluster,
+    random_batch,
+)
 
 try:
     from hypothesis import given, settings
@@ -190,14 +196,23 @@ def test_ten_thousand_candidates_single_call():
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k", [1, 4, 8])
-def test_multi_swap_netcost_bit_identical(k):
-    ba, _ = kernel_case(lambda: T.diamond(True), with_tm=False)
-    P0 = random_batch(ba, 12, seed=2)
+@pytest.mark.parametrize(
+    "case,k",
+    [pytest.param("diamond", k, id=str(k)) for k in (1, 4, 8)]
+    + [pytest.param(c, 8, id=f"{c}-8") for c in ANNEAL_CASES if c != "k8-tail"],
+)
+def test_multi_swap_netcost_bit_identical(case, k):
+    if case == "diamond":
+        ba, _ = kernel_case(lambda: T.diamond(True), with_tm=False)
+        P0, seed, t0 = random_batch(ba, 12, seed=2), 9, 2.0
+    else:
+        ba, P0, _, seed, _, t0 = anneal_case(case)
     # steps=30 is not a multiple of 4 or 8 — the k=1 tail chain runs too.
-    ref = BatchAnnealer(ba, backend="numpy").run(P0, 30, seed=9)
-    out = BatchAnnealer(ba, backend="jax").run(P0, 30, seed=9, multi_swap=k)
+    numpy_run, jax_run = BatchAnnealer(ba, backend="numpy"), BatchAnnealer(ba, backend="jax")
+    ref = numpy_run.run(P0, 30, seed=seed, t0=t0)
+    out = jax_run.run(P0, 30, seed=seed, t0=t0, multi_swap=k)
     assert np.array_equal(ref, out)
+    assert np.array_equal(np.asarray(numpy_run.accepted), np.asarray(jax_run.accepted))
 
 
 @pytest.mark.parametrize("k", [1, 4])
