@@ -16,7 +16,10 @@ configuration states, hard capacity.  The faults:
   answer is produced, onto the node that holds the most memory;
 * ``scramble`` — the decided topology's tasks trade nodes at random
   (a far worse placement);
-* ``miscount`` — the reported network cost leaves out same-rack pairs.
+* ``miscount`` — the reported network cost leaves out same-rack pairs;
+* ``misreport_tp`` — the sink throughput the program's fluid solve reports
+  is one part in a million high (a driver that carries the report reads it
+  in ``tp_gap``; no cell's driver does yet).
 
 The benchmark's own runs never load this module.
 """
@@ -32,7 +35,7 @@ from typing import Dict
 
 import numpy as np
 
-FAULTS = ("none", "float32", "overfill", "scramble", "miscount")
+FAULTS = ("none", "float32", "overfill", "scramble", "miscount", "misreport_tp")
 
 
 def _overfill(placements: Dict[str, str], topology, cluster, used: Dict[str, float]) -> None:
@@ -107,6 +110,17 @@ def planted(fault: str):
             return full - same_rack
 
         patch(Assignment, "network_cost", network_cost)
+    elif fault == "misreport_tp":
+        from repro.stream.simulator import Simulator
+
+        orig_result = Simulator._result
+
+        def result(self, *args, **kw):
+            out = orig_result(self, *args, **kw)
+            out.sink_throughput *= 1.0 + 1e-6
+            return out
+
+        patch(Simulator, "_result", result)
     elif fault == "float32":
         @contextlib.contextmanager
         def no_x64():

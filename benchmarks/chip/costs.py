@@ -15,8 +15,6 @@ the bound is HBM bandwidth.
 
 from __future__ import annotations
 
-from typing import Iterable
-
 WORD_BYTES = 4
 
 
@@ -44,9 +42,3 @@ def bytes_per_proposal(topology_spec: dict, hard_dims: int = 1) -> float:
         + 2 * 2 * hard_dims  # capacity and use of the two nodes
     )
     return words * WORD_BYTES
-
-
-def anneal_bytes(searches: Iterable[tuple]) -> float:
-    """Least bytes of a decision's searches: ``(topology_spec, chains,
-    steps)`` each."""
-    return sum(bytes_per_proposal(t) * chains * steps for t, chains, steps in searches)

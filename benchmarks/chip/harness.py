@@ -31,7 +31,7 @@ from typing import Optional
 
 import numpy as np
 
-from . import checks, costs, model, trace as trace_mod, traffic
+from . import checks, model, trace as trace_mod, traffic
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -96,14 +96,20 @@ class CompileCounter:
 
 
 class LayerContext:
-    """What a per-layer reader may read."""
+    """What a per-layer reader may read: the reduced device trace (None
+    without one), the compiles in the window, the number of traced
+    decisions, the chip's peaks, the traced decisions themselves
+    (``traffic.Decision``) and the driver that made them.  A reader of a
+    kernel's least work sums its own count over ``ctx.driver.searches(d)``
+    for ``d`` in ``ctx.decisions``."""
 
-    def __init__(self, trace, compiles, traced_decisions, anneal_bytes, peaks):
+    def __init__(self, trace, compiles, traced_decisions, peaks, decisions=(), driver=None):
         self.trace = trace
         self.compiles = compiles
         self.traced_decisions = traced_decisions
-        self.anneal_bytes = anneal_bytes
         self.peaks = peaks
+        self.decisions = decisions
+        self.driver = driver
 
 
 def _percentile(values, q: float) -> float:
@@ -233,8 +239,7 @@ def run_cell(
                 metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
     else:
         traced = decisions[: w["traced"]]
-        anneal_bytes = sum(costs.anneal_bytes(driver.searches(d)) for d in traced)
-        ctx = LayerContext(reduced, compiles, len(traced), anneal_bytes, peaks)
+        ctx = LayerContext(reduced, compiles, len(traced), peaks, decisions=traced, driver=driver)
         log(f"traced {len(traced)} decisions in {w['traced_s']!r} s; their mean "
             f"{sum(d.seconds for d in traced) / max(len(traced), 1)!r} s")
         for m in bench["per_layer"]:

@@ -72,12 +72,12 @@ def _synthetic_log():
 def test_span_readers_on_a_synthetic_log(reader, value):
     read = importlib.import_module(f"benchmarks.chip.layers.{reader}").read
     log = _synthetic_log()
-    assert read(LayerContext(None, 0, 2, 0, {}), log) == pytest.approx(value)
+    assert read(LayerContext(None, 0, 2, {}), log) == pytest.approx(value)
     # The greedy plan between the decisions is no decision.
-    assert read(LayerContext(None, 0, 1, 0, {}), log) == pytest.approx(value)
-    assert read(LayerContext(None, 0, 3, 0, {}), log) is None
-    assert read(LayerContext(None, 0, 1, 0, {}), log[:3]) is None
-    assert read(LayerContext(None, 0, 0, 0, {}), log) is None
+    assert read(LayerContext(None, 0, 1, {}), log) == pytest.approx(value)
+    assert read(LayerContext(None, 0, 3, {}), log) is None
+    assert read(LayerContext(None, 0, 1, {}), log[:3]) is None
+    assert read(LayerContext(None, 0, 0, {}), log) is None
 
 
 @pytest.mark.parametrize("cell_name", CELLS)

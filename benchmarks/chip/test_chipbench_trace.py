@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from benchmarks.chip import trace
+from benchmarks.chip import costs, tiny, trace, traffic
 from benchmarks.chip.harness import LayerContext
 from benchmarks.chip.layers import anneal_device_s, anneal_roofline, device_idle, score_device_s
 
@@ -45,15 +45,31 @@ def test_reduce_synthetic_window():
     assert gaps[0] == ["decision", pytest.approx(3.0)]  # 2.0 -> 5.0, the longest
     assert [g[1] for g in gaps] == sorted((g[1] for g in gaps), reverse=True)
     assert sum(g[1] for g in gaps) == pytest.approx(8.0 - 2.0)
-    ctx = LayerContext(r, 0, 2, 819e9 * 0.25, {"hbm_bytes_per_s": 819e9})
+    driver = _TwoScans()
+    decisions = [traffic.Decision(seconds=1.0, placements={})] * 2
+    ctx = LayerContext(r, 0, 2, {"hbm_bytes_per_s": 819e9}, decisions=decisions, driver=driver)
     assert device_idle.read(ctx) == pytest.approx(0.75)
     assert anneal_device_s.read(ctx) == pytest.approx(0.5)
     assert score_device_s.read(ctx) == pytest.approx(0.5)
-    assert anneal_roofline.read(ctx) == pytest.approx(25.0)
+    # Two decisions of one netcost search each; the throughput search runs
+    # another scan and is not counted; jit_anneal took 1 s.
+    least = 2 * costs.bytes_per_proposal(driver.SPEC) * 64 * 2000
+    assert anneal_roofline.read(ctx) == pytest.approx(100.0 * least / 819e9)
+
+
+class _TwoScans:
+    """A driver whose every decision ran one netcost and one throughput search."""
+
+    SPEC = tiny.config("chain1000-n256")["tenants"][0]
+
+    def searches(self, d):
+        return [(self.SPEC, 64, 2000, "netcost"), (self.SPEC, 64, 2000, "throughput")]
 
 
 def test_readers_find_nothing_without_a_trace():
-    ctx = LayerContext(None, 0, 3, 1e6, {"hbm_bytes_per_s": 819e9})
+    decisions = [traffic.Decision(seconds=1.0, placements={})] * 3
+    ctx = LayerContext(None, 0, 3, {"hbm_bytes_per_s": 819e9}, decisions=decisions,
+                       driver=_TwoScans())
     for reader in (device_idle, anneal_device_s, anneal_roofline, score_device_s):
         assert reader.read(ctx) is None
 

@@ -71,9 +71,10 @@ def test_reference_netcost_matches_the_numpy_scorer(name):
 
 @pytest.mark.parametrize("name", ["chain1000-n256", "linear-pair-n256"])
 def test_anneal_byte_count_is_a_lower_bound(name):
-    """The least bytes per proposal never exceed what the program's scan
-    reads for one proposal, and the degrees match its adjacency."""
+    """The least bytes per proposal never exceed what the program's netcost
+    scan reads for one proposal, and the degrees match its adjacency."""
     from repro.core import BatchArena, PlacementArena
+    from repro.core.search.anneal import scan_tables
 
     cfg = tiny.config(name)
     for tenant, spec in enumerate(cfg["tenants"]):
@@ -83,17 +84,18 @@ def test_anneal_byte_count_is_a_lower_bound(name):
         ba = BatchArena.from_arena(PlacementArena(cluster, topology), topology, placement)
         degs = costs.task_degrees(spec)
         assert sorted(degs) == sorted(ba.adj_mask.sum(axis=1).tolist())
-        width, dh = ba.adj.shape[1], ba.avail.shape[1]
+        net, avail, _, adj, _, _ = scan_tables(ba)
+        assert adj.dtype == np.int32
+        width, n_nodes, dh = adj.shape[1], net.shape[0], avail.shape[1]
         program = (
             2 * 4  # P[b, i], P[b, j] (int32 chains)
-            + 2 * width * (ba.adj.itemsize + ba.adj_mask.itemsize)  # adjacency rows
+            + 2 * width * adj.itemsize  # the two adjacency rows (padding is adj < 0)
             + 2 * width * 4  # neighbours' node ids
-            + 2 * 2 * width * ba.net.itemsize  # net-distance entries
-            + 2 * 2 * dh * ba.avail.itemsize  # capacity and use rows
+            + 2 * n_nodes * net.itemsize  # two net-distance rows, summed by node
+            + 2 * 2 * dh * avail.itemsize  # capacity and use rows
         )
         least = costs.bytes_per_proposal(spec, hard_dims=dh)
         assert 0 < least <= program
-    assert costs.anneal_bytes([(cfg["tenants"][0], 4, 10)]) == 40 * costs.bytes_per_proposal(cfg["tenants"][0])
 
 
 def test_run_exits_nonzero_without_a_tpu():

@@ -6,10 +6,10 @@ parameters.  The driver is a module of its own, ``drivers/<driver>.py``,
 found by that name; it defines ``Driver``, a subclass of :class:`Driver`
 below, which owns all that is particular to its kind of request: how a
 request is prepared and timed, greedy R-Storm's answer to the same request
-(the never-worse baseline), the searches a decision ran (for the annealer's
-byte count), and the loss and quality numbers of a decision.  The harness
-and the checks call these and never ask which driver they hold, so a new
-kind of traffic is a new driver file plus a traffic file.
+(the never-worse baseline), the searches a decision ran (for the layer
+readers' counts of work), and the loss and quality numbers of a decision.
+The harness and the checks call these and never ask which driver they hold,
+so a new kind of traffic is a new driver file plus a traffic file.
 
 Everything is derived from ``--seed``: the same seed gives the same requests.
 """
@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import model, reference
+from . import model, reference, reference_tp
 
 HERE = Path(__file__).resolve().parent
 
@@ -51,6 +51,9 @@ class Decision:
     #: tenant index in the configuration of each topology id.
     tenant_of: Dict[str, int] = dataclasses.field(default_factory=dict)
     reported_cost: Optional[float] = None
+    #: the sink throughput the program reports for the decided topology,
+    #: where the driver has one.
+    reported_tp: Optional[float] = None
     #: nodes that were dead when the decision was made.
     dead: tuple = ()
     #: greedy R-Storm's answer to the same request (same ids), where the
@@ -101,10 +104,17 @@ class Driver:
         and the tasks it left unplaced."""
         raise NotImplementedError
 
+    @property
+    def objective(self) -> str:
+        """The objective the traffic's scheduler names (netcost where none)."""
+        return self.spec.get("scheduler", {}).get("kwargs", {}).get("objective", "netcost")
+
     def searches(self, d: Decision) -> list:
-        """``(topology spec, chains, steps)`` of the searches ``d`` ran."""
+        """``(topology spec, chains, steps, objective)`` of the searches
+        ``d`` ran."""
         kw = self.spec["scheduler"]["kwargs"]
-        return [(self.cfg["tenants"][d.tenant_of[d.decided]], kw["n_chains"], kw["steps"])]
+        spec = self.cfg["tenants"][d.tenant_of[d.decided]]
+        return [(spec, kw["n_chains"], kw["steps"], self.objective)]
 
     def netcost(self, topos, cluster, placements) -> float:
         """Cluster-wide network cost, by the plain reference."""
@@ -115,9 +125,29 @@ class Driver:
 
     def judge(self, d: Decision, topos, cluster, greedy) -> Tuple[float, Dict[str, float]]:
         """The relative loss of ``d`` against greedy in the objective the
-        request names, and the decision's quality numbers.  The default is
-        a netcost search's: loss and ``placed_netcost`` by the reference."""
+        request names, and the decision's quality numbers.
+
+        A netcost loss is exact; quality is the cluster-wide
+        ``placed_netcost`` by the plain reference.  A throughput loss is in
+        the decided topology's sink throughput alone on the cluster, as the
+        program's never-worse check simulates it, and counts only beyond
+        ``reference_tp.TOLERANCE``: the program's fluid solve stops within
+        1e-9 of its fixed point, so two of them may rank two placements
+        that far apart the wrong way.  Quality then adds ``placed_tp``,
+        every tenant's summed sink throughput solved jointly."""
         cost = self.netcost(topos, cluster, d.placements)
+        if self.objective == "throughput":
+            tenant = d.tenant_of[d.decided]
+            mine = reference_tp.alone(self.cfg, d.decided, d.placements[d.decided], tenant, d.dead)
+            base = reference_tp.alone(self.cfg, d.decided, greedy[d.decided], tenant, d.dead)
+            loss = (base - mine) / base
+            quality = {
+                "placed_netcost": cost,
+                "placed_tp": reference_tp.cluster_throughput(
+                    self.cfg, d.placements, d.tenant_of, d.dead
+                ),
+            }
+            return (loss if loss > reference_tp.TOLERANCE else 0.0), quality
         base = self.netcost(topos, cluster, greedy)
         return (cost - base) / base, {"placed_netcost": cost}
 
